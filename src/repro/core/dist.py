@@ -22,10 +22,8 @@ from typing import Sequence
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
-
-from repro.core import compat
 
 
 BATCH_AXES = ("pod", "data")  # axes that shard the batch dimension
@@ -34,7 +32,9 @@ MODEL_AXIS = "model"  # the TATP ring axis
 
 def make_mesh(shape: Sequence[int], names: Sequence[str],
               devices=None) -> Mesh:
-    return compat.make_mesh(shape, names, devices=devices)
+    return jax.make_mesh(tuple(shape), tuple(names),
+                         axis_types=(AxisType.Auto,) * len(names),
+                         devices=devices)
 
 
 @dataclass(frozen=True)

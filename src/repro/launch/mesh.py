@@ -6,21 +6,36 @@ importing this module touches no jax device state.
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
 
-from repro.core import compat
-from repro.core.dist import Dist
+from repro.core.dist import Dist, make_mesh
+
+
+def init_compile_cache() -> None:
+    """Keep JAX's persistent compilation cache at one fixed path.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives in ``.jax_cache`` at
+    the root of the checkout.  Entry points call this before their first
+    compile.
+    """
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        root = Path(__file__).resolve().parents[3]
+        jax.config.update("jax_compilation_cache_dir",
+                          str(root / ".jax_cache"))
 
 
 def make_production_mesh(*, multi_pod: bool = False,
                          devices: Optional[Sequence] = None) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(shape, axes, devices=devices)
+    return make_mesh(shape, axes, devices=devices)
 
 
 def make_wafer_ordered_mesh(order: np.ndarray, *,
@@ -68,7 +83,7 @@ def make_plan_mesh(plan, devices: Optional[Sequence] = None) -> Mesh:
     devs = list(devices) if devices is not None else list(jax.devices())
     data, model = plan.mesh_shape_for(len(devs))
     devs = [devs[i] for i in plan_device_permutation(plan, len(devs))]
-    return compat.make_mesh((data, model), ("data", "model"), devices=devs)
+    return make_mesh((data, model), ("data", "model"), devices=devs)
 
 
 def stage_device_partition(plan, n_devices: int) -> list[list[int]]:

@@ -47,10 +47,13 @@ import numpy as np
 
 
 def _build_bundle(cfg, mesh, par, max_batch: int, max_seq: int):
+    """Step functions, sharded random params, and the zeroed resident
+    decode cache at its global shape, laid out as ``decode_fn`` takes it
+    (``cache_specs``), plus that layout for re-placing grafted caches."""
     from repro.configs.base import ShapeConfig
     from repro.core.dist import Dist
     from repro.models.transformer import init_params
-    from repro.train.train_loop import make_serve_fns
+    from repro.train.train_loop import cache_shapes, make_serve_fns
     from jax.sharding import NamedSharding
 
     dist = Dist(mesh)
@@ -58,7 +61,34 @@ def _build_bundle(cfg, mesh, par, max_batch: int, max_seq: int):
     sb = make_serve_fns(cfg, par, dist, shape)
     params = jax.jit(lambda k: init_params(k, cfg), out_shardings=jax.tree.map(
         lambda s: NamedSharding(mesh, s), sb.pspecs))(jax.random.key(0))
-    return sb, params, dist
+    cache_sh = jax.tree.map(lambda s: NamedSharding(mesh, s), sb.cspecs)
+    shapes = cache_shapes(cfg, shape, dist)
+    caches = jax.jit(lambda: jax.tree.map(
+        lambda a: jnp.zeros(a.shape, a.dtype), shapes),
+        out_shardings=cache_sh)()
+    return sb, params, caches, cache_sh
+
+
+def release(tree):
+    """Free a device pytree now, not when the last reference dies: at
+    serving widths the resident cache and the params are most of HBM, and
+    a second copy must not coexist with the first."""
+    for x in jax.tree.leaves(tree):
+        if isinstance(x, jax.Array):
+            x.delete()
+
+
+def _graft_to_device(big, small, slots, rows, sharding):
+    """Graft ``small``'s rows into ``big``'s slots on the host
+    (:func:`repro.models.lm.graft_cache_slots`) and place the result
+    under the decode layout.  Both device trees are freed before the
+    merged one is placed, so the resident cache never exists twice."""
+    from repro.models import lm
+    host_big, host_small = jax.device_get((big, small))
+    release(big)
+    release(small)
+    merged = lm.graft_cache_slots(host_big, host_small, slots, rows=rows)
+    return jax.device_put(merged, sharding)
 
 
 # ---------------------------------------------------------------------------
@@ -80,24 +110,15 @@ class JaxServeExecutor:
     """
 
     def __init__(self, plan, cfg, *, mesh=None):
-        from dataclasses import replace
         from repro.launch.mesh import make_plan_mesh
-        from repro.models import lm
-        from repro.models.transformer import RunCtx
 
         self.plan = plan
         self.cfg = cfg
         mesh = mesh if mesh is not None else make_plan_mesh(plan.plan)
-        par = replace(plan.parallel_config(), remat=False)
-        self.sb, self.params, dist = _build_bundle(
-            cfg, mesh, par, plan.max_batch, plan.max_seq)
-        self._dec_ctx = RunCtx(cfg, par, dist, phase="decode")
-        bl = plan.max_batch // max(dist.batch_degree, 1) \
-            if plan.max_batch % max(dist.batch_degree, 1) == 0 \
-            else plan.max_batch
-        self.caches = lm.init_cache(self._dec_ctx, bl, plan.max_seq,
-                                    enc_len=cfg.frontend_tokens or None)
+        self.sb, self.params, self.caches, self._cache_sh = _build_bundle(
+            cfg, mesh, plan.parallel_config(), plan.max_batch, plan.max_seq)
         self.last_tok = np.zeros(plan.max_batch, np.int32)
+        self.last_logits = None  # decode logits of the latest step
         self._rng = np.random.RandomState(0)
 
     def _prompt(self, req):
@@ -117,7 +138,10 @@ class JaxServeExecutor:
         return None  # wall clock: real elapsed time stands
 
     def _prefill_group(self, states):
-        from repro.models import lm
+        """Prefill one same-length group, graft it into the resident
+        cache and take each row's first token.  Returns the group's
+        last-position logits ([max_batch, 1, padded vocab]; rows past
+        ``len(states)`` are padding)."""
         cfg, plan = self.cfg, self.plan
         plen = states[0].req.prompt_len
         toks = np.zeros((plan.max_batch, plen), np.int64)
@@ -133,16 +157,15 @@ class JaxServeExecutor:
                 self._rng.randn(plan.max_batch, cfg.frontend_tokens,
                                 cfg.d_model).astype(cfg.dtype) * 0.02)
         small, logits = self.sb.prefill_fn(self.params, pre)
-        slots = [st.slot for st in states]
-        merged = lm.graft_cache_slots(jax.device_get(self.caches),
-                                      jax.device_get(small), slots,
-                                      rows=range(len(states)))
-        self.caches = jax.tree.map(jnp.asarray, merged)
+        self.caches = _graft_to_device(self.caches, small,
+                                       [st.slot for st in states],
+                                       range(len(states)), self._cache_sh)
         first = np.asarray(jnp.argmax(logits[:, -1, :], axis=-1)) \
             % cfg.vocab_size
         for i, st in enumerate(states):
             st.tokens.append(int(first[i]))
             self.last_tok[st.slot] = first[i]
+        return logits
 
     def decode(self, states):
         toks = np.zeros((self.plan.max_batch, 1), np.int32)
@@ -150,7 +173,7 @@ class JaxServeExecutor:
         for st in states:
             toks[st.slot, 0] = self.last_tok[st.slot]
             clen[st.slot] = st.context_len  # prompt + generated so far
-        nxt, _, self.caches = self.sb.decode_fn(
+        nxt, self.last_logits, self.caches = self.sb.decode_fn(
             self.params, jnp.asarray(toks), self.caches,
             jnp.asarray(clen))
         nxt = np.asarray(nxt)[:, 0]
@@ -173,31 +196,25 @@ class JaxServeExecutor:
         stable across replans, so K/V windows copy row-for-row.  Returns
         None: under a WallClock the real rebuild+graft time stands.
         """
-        from dataclasses import replace
         from repro.launch.mesh import make_plan_mesh
-        from repro.models import lm
-        from repro.models.transformer import RunCtx
 
+        # the old params and cache leave the device before the new
+        # bundle is built: two copies of either do not fit at serving
+        # widths (params are re-made from the same seed)
         old_caches = jax.device_get(self.caches)
+        release((self.params, self.caches))
+        self.params = self.caches = None
         old_last = self.last_tok
-        cfg = self.cfg
         self.plan = new_plan
-        mesh = make_plan_mesh(new_plan.plan)
-        par = replace(new_plan.parallel_config(), remat=False)
-        self.sb, self.params, dist = _build_bundle(
-            cfg, mesh, par, new_plan.max_batch, new_plan.max_seq)
-        self._dec_ctx = RunCtx(cfg, par, dist, phase="decode")
-        bl = new_plan.max_batch // max(dist.batch_degree, 1) \
-            if new_plan.max_batch % max(dist.batch_degree, 1) == 0 \
-            else new_plan.max_batch
-        fresh = lm.init_cache(self._dec_ctx, bl, new_plan.max_seq,
-                              enc_len=cfg.frontend_tokens or None)
+        self.sb, self.params, fresh, self._cache_sh = _build_bundle(
+            self.cfg, make_plan_mesh(new_plan.plan),
+            new_plan.parallel_config(), new_plan.max_batch,
+            new_plan.max_seq)
         if mig.survivors:
             slots = [new_slot for _, _, new_slot in mig.survivors]
             rows = [old_slot for _, old_slot, _ in mig.survivors]
-            merged = lm.graft_cache_slots(jax.device_get(fresh),
-                                          old_caches, slots, rows=rows)
-            self.caches = jax.tree.map(jnp.asarray, merged)
+            self.caches = _graft_to_device(fresh, old_caches, slots, rows,
+                                           self._cache_sh)
         else:
             self.caches = fresh
         self.last_tok = np.zeros(new_plan.max_batch, np.int32)
@@ -281,8 +298,6 @@ def serve(args) -> dict:
     from repro.configs import get_config, get_reduced
     from repro.configs.base import ParallelConfig
     from repro.core.dist import make_mesh
-    from repro.models import lm
-    from repro.models.transformer import RunCtx
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     max_seq = args.prompt_len + args.gen
@@ -298,7 +313,8 @@ def serve(args) -> dict:
         names = ("data", "model")[: len(args.mesh)]
         mesh = make_mesh(tuple(args.mesh), names)
         par = ParallelConfig(strategy="tatp", remat=False)
-    sb, params, dist = _build_bundle(cfg, mesh, par, args.batch, max_seq)
+    sb, params, big, cache_sh = _build_bundle(cfg, mesh, par, args.batch,
+                                              max_seq)
 
     rng = np.random.RandomState(0)
     prompts = rng.randint(0, cfg.vocab_size, (args.batch, args.prompt_len))
@@ -314,20 +330,10 @@ def serve(args) -> dict:
             rng.randn(args.batch, cfg.frontend_tokens, cfg.d_model)
             .astype(cfg.dtype) * 0.02)
 
-    # simple path: prefill produces prompt-length caches; graft into the
-    # max_seq layout
-    caches, logits = sb.prefill_fn(params, pre_batch)
-    big = lm.init_cache(RunCtx(cfg, par, dist, phase="decode"),
-                        args.batch // max(dist.batch_degree, 1)
-                        if args.batch % max(dist.batch_degree, 1) == 0
-                        else args.batch,
-                        max_seq, enc_len=cfg.frontend_tokens or None)
-
-    # merge on host to respect shardings of the decode layout (the shared
-    # continuous-batching graft, applied to every slot at once)
-    caches = jax.tree.map(jnp.asarray, lm.graft_cache_slots(
-        jax.device_get(big), jax.device_get(caches),
-        slots=range(args.batch)))
+    # prefill produces prompt-length caches; graft them into the max_seq
+    # layout (the continuous-batching graft, applied to every slot at once)
+    small, logits = sb.prefill_fn(params, pre_batch)
+    caches = _graft_to_device(big, small, range(args.batch), None, cache_sh)
 
     toks = jnp.argmax(logits[:, -1:, :], axis=-1).astype(jnp.int32) \
         % cfg.vocab_size
@@ -424,6 +430,8 @@ def main():
                          "chunk boundaries (intra-step preemption); "
                          "default: single-pass prefill")
     args = ap.parse_args()
+    from repro.launch.mesh import init_compile_cache
+    init_compile_cache()
     if args.serve:
         print(json.dumps(serve_engine(args)))
     else:

@@ -220,6 +220,8 @@ def main():
     ap.add_argument("--log-every", type=int, default=5)
     ap.add_argument("--fail-at-step", type=int, default=None)
     args = ap.parse_args()
+    from repro.launch.mesh import init_compile_cache
+    init_compile_cache()
     summary = train(args)
     print(json.dumps(summary))
 

@@ -519,13 +519,9 @@ def _stage1_jax_fn(fsdp: bool, has_kv: bool):
 
 
 def _stage1_jax(ctx: StepCostContext, dp, tp, sp, ta, seq_par) -> dict:
-    """Stage 1 on the jax backend (jitted; see :func:`_stage1_jax_fn`).
-    Falls back to numpy when jax is unavailable."""
+    """Stage 1 on the jax backend (jitted; see :func:`_stage1_jax_fn`)."""
     cfg = ctx.cfg
-    try:
-        fn = _stage1_jax_fn(ctx.fsdp, bool(cfg.n_kv_heads))
-    except ImportError:  # container without jax: stay on the numpy path
-        return _stage1_numpy(ctx, dp, tp, sp, ta, seq_par)
+    fn = _stage1_jax_fn(ctx.fsdp, bool(cfg.n_kv_heads))
     out = fn(dp, tp, sp, ta, seq_par, ctx.n_dies, float(ctx.p_total),
              float(ctx.p_layer), float(ctx.p_active), float(ctx.tokens),
              ctx.batch, ctx.n_l, cfg.d_model, cfg.kv_dim,
@@ -540,27 +536,12 @@ def _stage1_jax(ctx: StepCostContext, dp, tp, sp, ta, seq_par) -> dict:
 # fully-jitted Tier B (stage 1 + stage 2 fused; opt-in via tierb="jax")
 # ---------------------------------------------------------------------------
 
-_TIERB_JAX_OK: Optional[bool] = None  # None = jax not probed yet
-
 
 def _jax_setup():
-    """Import jax for the jitted engine tiers: flips x64 on (the engine is
-    float64 end-to-end) and points the persistent compilation cache at
-    ``REPRO_JAX_CACHE_DIR`` when set, so repeat processes (CI lanes, sweep
-    restarts) skip recompilation."""
+    """Import jax for the jitted engine tiers and flip x64 on (the engine
+    is float64 end-to-end)."""
     import jax
     jax.config.update("jax_enable_x64", True)
-    cache_dir = os.environ.get("REPRO_JAX_CACHE_DIR")
-    if cache_dir:
-        try:
-            jax.config.update("jax_compilation_cache_dir",
-                              os.path.expanduser(cache_dir))
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0)
-        except Exception:  # older jax without the persistent-cache knobs
-            pass
     return jax
 
 
@@ -571,14 +552,7 @@ def _jit_exact(jax, f):
     inexact — on degraded wafers every hop-factor product is.  Disabling
     excess precision keeps every multiply and add individually rounded,
     exactly like numpy."""
-    try:
-        return jax.jit(
-            f, compiler_options={"xla_allow_excess_precision": False})
-    except TypeError:
-        # jax too old for per-jit compiler options: the strict-IEEE pin
-        # is unavailable, so refuse the jitted tier rather than risk
-        # 1-ulp drift vs the anchors (callers fall back to numpy)
-        raise ImportError("jax.jit lacks compiler_options")
+    return jax.jit(f, compiler_options={"xla_allow_excess_precision": False})
 
 
 @lru_cache(maxsize=None)
@@ -868,25 +842,15 @@ def _tierb_scalars(ctx: StepCostContext) -> dict:
 
 
 def _tierb_jax(ctx: StepCostContext,
-               degrees: list[ParallelDegrees]) -> Optional[dict]:
+               degrees: list[ParallelDegrees]) -> dict:
     """Run the fused jitted Tier-B over one (feasible) candidate list.
 
-    Returns the stage-1 fields plus the assembled stage-2 column rows, or
-    ``None`` when jax is unavailable (permanent numpy fallback)."""
-    global _TIERB_JAX_OK
-    if _TIERB_JAX_OK is False:
-        return None
+    Returns the stage-1 fields plus the assembled stage-2 column rows."""
     st = _batch_struct(ctx, degrees)
     kb = max(int(ctx.batch).bit_length() + 1, 1)
-    try:
-        fn = _tierb_jax_fn(tuple(st["active"]), bool(st["exposed"]),
-                           st["dp_any"], ctx.tatp_bidirectional,
-                           ctx.stream, ctx.fsdp, bool(ctx.cfg.n_kv_heads),
-                           kb)
-    except ImportError:  # container without jax: stay on the numpy tier
-        _TIERB_JAX_OK = False
-        return None
-    _TIERB_JAX_OK = True
+    fn = _tierb_jax_fn(tuple(st["active"]), bool(st["exposed"]),
+                       st["dp_any"], ctx.tatp_bidirectional,
+                       ctx.stream, ctx.fsdp, bool(ctx.cfg.n_kv_heads), kb)
     nc = len(degrees)
     ncp = max(8, 1 << (nc - 1).bit_length())  # pow2 shape bucket
     jst = st.get("_jax")
@@ -2468,18 +2432,10 @@ def _decode_scalars(ctx: StepCostContext) -> dict:
 
 def _decode_jax(ctx: StepCostContext, dkey: tuple, arrs: tuple,
                 hkey: tuple, ta_hops: np.ndarray, sp_hops: np.ndarray,
-                eff: np.ndarray) -> Optional[np.ndarray]:
+                eff: np.ndarray) -> np.ndarray:
     """Run the jitted decode kernel over one candidate list; returns the
-    (11, nC) component matrix or ``None`` when jax is unavailable."""
-    global _TIERB_JAX_OK
-    if _TIERB_JAX_OK is False:
-        return None
-    try:
-        fn = _decode_jax_fn()
-    except ImportError:  # container without jax: numpy tier
-        _TIERB_JAX_OK = False
-        return None
-    _TIERB_JAX_OK = True
+    (11, nC) component matrix."""
+    fn = _decode_jax_fn()
     import jax.numpy as jnp
     nC = len(arrs[0])
     ncp = max(8, 1 << (nC - 1).bit_length())

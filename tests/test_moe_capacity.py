@@ -86,11 +86,16 @@ def test_dropped_tokens_pass_residual_unchanged():
 
 def test_high_capacity_admits_everything():
     """capacity_factor = E lifts cap to 8: no drops, and the originally
-    admitted token's output is bitwise-unchanged (same expert, same
-    buffer row)."""
+    admitted token's output is unchanged (same expert, same buffer row)
+    up to f32 rounding."""
     y_lo, y_hi = _run(0.5), _run(float(E))
     assert np.all(np.any(y_hi != 0.0, axis=1))  # every token got output
-    assert np.array_equal(y_lo[0], y_hi[0])
+    # bitwise equality across buffer sizes is not promised: XLA may tile
+    # the expert einsums differently for an [E, 1, D] buffer and an
+    # [E, 8, D] one, so the f32 sums over D and F round in another order;
+    # bound the difference by (D + F) roundings of the largest output
+    atol = (D + F) * np.finfo(np.float32).eps * np.abs(y_lo[0]).max()
+    np.testing.assert_allclose(y_hi[0], y_lo[0], rtol=0, atol=atol)
     # and capacity is the only difference: admitted rows all run through
     # the same single expert, so equal inputs give equal outputs
     x = np.asarray(_x()).reshape(T, D)

@@ -10,7 +10,7 @@ import pytest
 
 HERE = os.path.dirname(__file__)
 SCRIPTS = ["check_tatp.py", "check_model.py", "check_zigzag.py",
-           "check_wire_grads.py", "check_megatron.py"]
+           "check_wire_grads.py", "check_megatron.py", "check_serve.py"]
 
 
 @pytest.mark.parametrize("script", SCRIPTS)

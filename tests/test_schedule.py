@@ -2,11 +2,8 @@
 
 import pytest
 
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # deterministic fallback; no pip installs in-container
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.schedule import (PipeEvent, gpipe_schedule, line_schedule,
                                  one_f_one_b_schedule,

@@ -389,10 +389,15 @@ def test_mixed_cache_len_rows_match_isolated_decodes():
     n1, l1, _ = step(params, t1, caches1, jnp.int32(s1 + 1))
     assert int(n_b[0, 0]) == int(n0[0, 0])
     assert int(n_b[1, 0]) == int(n1[0, 0])
-    np.testing.assert_allclose(np.asarray(l_b[0], np.float32),
-                               np.asarray(l0[0], np.float32), rtol=2e-4)
-    np.testing.assert_allclose(np.asarray(l_b[1], np.float32),
-                               np.asarray(l1[0], np.float32), rtol=2e-4)
+    # the batched and the isolated step sum the same f32 products over
+    # d_model in another order (different batch shapes, different XLA
+    # tiling), so logits near zero can differ by d_model roundings of the
+    # largest logit: the relative bound alone cannot hold there
+    for got, ref in ((l_b[0], l0[0]), (l_b[1], l1[0])):
+        ref = np.asarray(ref, np.float32)
+        atol = cfg.d_model * np.finfo(np.float32).eps * np.abs(ref).max()
+        np.testing.assert_allclose(np.asarray(got, np.float32), ref,
+                                   rtol=2e-4, atol=atol)
 
 
 @pytest.mark.slow
@@ -423,3 +428,19 @@ def test_graft_cache_slots_touches_only_target_slots():
     np.testing.assert_array_equal(out["k"][:, 1, :4], small["k"][:, 0])
     np.testing.assert_array_equal(out["k"][:, 1, 4:], big["k"][:, 1, 4:])
     np.testing.assert_array_equal(out["state"][:, 3], small["state"][:, 1])
+
+
+def test_chip_smoke_refuses_without_tpu():
+    """chip_smoke.py has no CPU fallback: on a machine where JAX finds no
+    TPU it exits non-zero, names the platform it found, and prints no
+    result line."""
+    import os
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run([sys.executable, os.path.join(root, "chip_smoke.py")],
+                         capture_output=True, text=True, env=env, timeout=300)
+    assert out.returncode != 0
+    assert "platform 'cpu'" in out.stderr
+    assert '"ok"' not in out.stdout

@@ -5,11 +5,8 @@ test_multidevice.py subprocesses."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # deterministic fallback; no pip installs in-container
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import tatp
 
