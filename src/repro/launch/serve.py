@@ -45,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.serve.spans import NULL as NULL_RECORDER, span
+
 
 def _build_bundle(cfg, mesh, par, max_batch: int, max_seq: int):
     """Step functions, sharded random params, and the zeroed resident
@@ -78,17 +80,36 @@ def release(tree):
             x.delete()
 
 
+def _nbytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree.leaves(tree))
+
+
 def _graft_to_device(big, small, slots, rows, sharding):
     """Graft ``small``'s rows into ``big``'s slots on the host
     (:func:`repro.models.lm.graft_cache_slots`) and place the result
     under the decode layout.  Both device trees are freed before the
-    merged one is placed, so the resident cache never exists twice."""
+    merged one is placed, so the resident cache never exists twice.
+
+    The ``serve.graft`` span records the bytes over the host link
+    (``d2h_bytes``, ``h2d_bytes``: every leaf's global size).  Its
+    ``fetch`` part also waits for the prefill that made ``small``; its
+    ``place`` part ends when ``device_put`` returns, so the rest of the
+    copy is waited for by the next decode step."""
     from repro.models import lm
-    host_big, host_small = jax.device_get((big, small))
-    release(big)
-    release(small)
-    merged = lm.graft_cache_slots(host_big, host_small, slots, rows=rows)
-    return jax.device_put(merged, sharding)
+    info: dict = {}
+    with span("serve.graft", info):
+        with span("serve.graft.fetch"):
+            host_big, host_small = jax.device_get((big, small))
+        info["d2h_bytes"] = _nbytes((host_big, host_small))
+        release(big)
+        release(small)
+        with span("serve.graft.merge"):
+            merged = lm.graft_cache_slots(host_big, host_small, slots,
+                                          rows=rows)
+        info["h2d_bytes"] = _nbytes(merged)
+        with span("serve.graft.place"):
+            placed = jax.device_put(merged, sharding)
+    return placed
 
 
 # ---------------------------------------------------------------------------
@@ -107,7 +128,13 @@ class JaxServeExecutor:
     (:func:`repro.models.lm.graft_cache_slots`), leaving every other
     in-flight request's state untouched.  Per-slot context positions go
     into the decode step as the ``cache_len`` vector.
+
+    ``spans`` is the recorder (:mod:`repro.serve.spans`) that
+    :meth:`ServeEngine.run` makes active for a run: the no-op one unless a
+    caller sets its own.
     """
+
+    spans = NULL_RECORDER
 
     def __init__(self, plan, cfg, *, mesh=None):
         from repro.launch.mesh import make_plan_mesh
@@ -156,7 +183,9 @@ class JaxServeExecutor:
             pre["enc_embeds"] = jnp.asarray(
                 self._rng.randn(plan.max_batch, cfg.frontend_tokens,
                                 cfg.d_model).astype(cfg.dtype) * 0.02)
-        small, logits = self.sb.prefill_fn(self.params, pre)
+        with span("serve.prefill", {"rows": len(states),
+                                    "padded_rows": plan.max_batch}):
+            small, logits = self.sb.prefill_fn(self.params, pre)
         self.caches = _graft_to_device(self.caches, small,
                                        [st.slot for st in states],
                                        range(len(states)), self._cache_sh)
@@ -176,7 +205,8 @@ class JaxServeExecutor:
         nxt, self.last_logits, self.caches = self.sb.decode_fn(
             self.params, jnp.asarray(toks), self.caches,
             jnp.asarray(clen))
-        nxt = np.asarray(nxt)[:, 0]
+        with span("serve.decode.wait"):
+            nxt = np.asarray(nxt)[:, 0]
         for st in states:
             st.tokens.append(int(nxt[st.slot]))
             self.last_tok[st.slot] = nxt[st.slot]

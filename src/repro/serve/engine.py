@@ -51,6 +51,8 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Optional, Sequence
 
+from repro.serve.spans import recording, span
+
 
 @dataclass(frozen=True)
 class Request:
@@ -728,8 +730,10 @@ class ServeEngine:
     ``executor`` provides ``prefill(states) -> duration`` and
     ``decode(states) -> duration`` (return None under a WallClock to let
     real elapsed time stand), and optionally ``migrate(new_plan, mig,
-    wafer) -> duration`` for fault recovery.  ``on_iteration`` /
-    ``on_recovery`` are optional hooks for logging/tracing.
+    wafer) -> duration`` for fault recovery and ``spans``, the recorder
+    (:mod:`repro.serve.spans`) that :meth:`run` makes active.
+    ``on_iteration`` / ``on_recovery`` are optional hooks for
+    logging/tracing.
 
     Elastic serving: pass ``faults`` (a timeline of :class:`FaultEvent`)
     plus the model ``cfg`` the plan was compiled for.  When an event
@@ -995,6 +999,12 @@ class ServeEngine:
 
     def run(self, requests: Sequence[Request],
             max_iterations: int = 1_000_000) -> ServeReport:
+        """Serve ``requests``; the executor's ``spans`` recorder, where it
+        has one, records the run's spans."""
+        with recording(getattr(self.executor, "spans", None)):
+            return self._run(requests, max_iterations)
+
+    def _run(self, requests, max_iterations) -> ServeReport:
         import dataclasses
         sched, clock, gov = self.sched, self.clock, self.gov
         t0 = clock.now()
@@ -1008,71 +1018,72 @@ class ServeEngine:
             for ev in self.faults)
         i = 0
         for _ in range(max_iterations):
-            now = clock.now()
-            while fault_q and fault_q[0].time <= now:
-                ev = fault_q.popleft()
-                if gov is None:
-                    now = self._recover(ev, now)
-                else:
-                    gov.observe(ev)
-            if gov is not None:
-                dec = gov.decide(now, plan=self.plan, wafer=self.wafer,
-                                 cfg=self.cfg,
-                                 cache_dir=self.plan_cache_dir)
-                if dec is not None:
-                    if dec.action == "replan":
-                        now = self._recover(dec.event, now,
-                                            reason=dec.reason,
-                                            cached=dec.cached)
-                    elif dec.action == "apply":
-                        self._absorb(dec.event)
-                    # "noop": the coalesced events cancelled out
-            while i < len(pending) and pending[i].arrival <= now:
-                sched.submit(pending[i])
-                i += 1
-            sched.reject_never_fit(now)
-            if sched.drained and i == len(pending) and \
-                    (gov is None or (not fault_q and not gov.pending)):
-                break
-            newly = sched.admit(now)
-            if self._chunked:
-                # resumed partial prefills ride along with fresh admits
-                prefills = [sched.active[s] for s in sorted(sched.active)
-                            if sched.active[s].tokens_done == 0]
-            else:
-                prefills = newly
-            if prefills:
-                now = self._prefill(prefills, now)
-            batch = sched.decode_batch()
-            if batch:
-                t_before = now
-                dt = self.executor.decode(batch)
-                now = clock.advance(dt)
-                sched.mark_decoded(batch, now)
-                self._sample(now, len(batch), now - t_before, "decode")
-                if self.router is not None:
-                    self.router.observe(len(batch))
-            elif not prefills:
-                # nothing in flight and head-of-line blocked or queue
-                # empty: jump to the next arrival, scheduled fault, or
-                # pending governor deadline (coalesce/backoff expiry)
-                horizon = []
-                if i < len(pending):
-                    horizon.append(pending[i].arrival)
-                if fault_q:
-                    horizon.append(fault_q[0].time)
+            with span("serve.iteration"):
+                now = clock.now()
+                while fault_q and fault_q[0].time <= now:
+                    ev = fault_q.popleft()
+                    if gov is None:
+                        now = self._recover(ev, now)
+                    else:
+                        gov.observe(ev)
                 if gov is not None:
-                    d = gov.next_deadline()
-                    if d is not None:
-                        horizon.append(d)
-                if horizon:
-                    clock.wait_until(min(horizon))
-                elif sched.waiting:
-                    # unreachable: never-fit heads were rejected above and
-                    # an idle mesh always has headroom for a fitting head
-                    raise RuntimeError(
-                        f"scheduler deadlock: request "
-                        f"{sched.waiting[0].rid} blocked on an idle mesh")
+                    dec = gov.decide(now, plan=self.plan, wafer=self.wafer,
+                                     cfg=self.cfg,
+                                     cache_dir=self.plan_cache_dir)
+                    if dec is not None:
+                        if dec.action == "replan":
+                            now = self._recover(dec.event, now,
+                                                reason=dec.reason,
+                                                cached=dec.cached)
+                        elif dec.action == "apply":
+                            self._absorb(dec.event)
+                        # "noop": the coalesced events cancelled out
+                while i < len(pending) and pending[i].arrival <= now:
+                    sched.submit(pending[i])
+                    i += 1
+                sched.reject_never_fit(now)
+                if sched.drained and i == len(pending) and \
+                        (gov is None or (not fault_q and not gov.pending)):
+                    break
+                newly = sched.admit(now)
+                if self._chunked:
+                    # resumed partial prefills ride along with fresh admits
+                    prefills = [sched.active[s] for s in sorted(sched.active)
+                                if sched.active[s].tokens_done == 0]
+                else:
+                    prefills = newly
+                if prefills:
+                    now = self._prefill(prefills, now)
+                batch = sched.decode_batch()
+                if batch:
+                    t_before = now
+                    dt = self.executor.decode(batch)
+                    now = clock.advance(dt)
+                    sched.mark_decoded(batch, now)
+                    self._sample(now, len(batch), now - t_before, "decode")
+                    if self.router is not None:
+                        self.router.observe(len(batch))
+                elif not prefills:
+                    # nothing in flight and head-of-line blocked or queue
+                    # empty: jump to the next arrival, scheduled fault, or
+                    # pending governor deadline (coalesce/backoff expiry)
+                    horizon = []
+                    if i < len(pending):
+                        horizon.append(pending[i].arrival)
+                    if fault_q:
+                        horizon.append(fault_q[0].time)
+                    if gov is not None:
+                        d = gov.next_deadline()
+                        if d is not None:
+                            horizon.append(d)
+                    if horizon:
+                        clock.wait_until(min(horizon))
+                    elif sched.waiting:
+                        # unreachable: never-fit heads were rejected above and
+                        # an idle mesh always has headroom for a fitting head
+                        raise RuntimeError(
+                            f"scheduler deadlock: request "
+                            f"{sched.waiting[0].rid} blocked on an idle mesh")
             if self.on_iteration:
                 self.on_iteration(self)
         self._finalize_events(clock.now())
