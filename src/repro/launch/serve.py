@@ -84,17 +84,19 @@ def _nbytes(tree) -> int:
     return sum(x.nbytes for x in jax.tree.leaves(tree))
 
 
-def _graft_to_device(big, small, slots, rows, sharding):
+def _graft_via_host(big, small, slots, rows, sharding):
     """Graft ``small``'s rows into ``big``'s slots on the host
     (:func:`repro.models.lm.graft_cache_slots`) and place the result
     under the decode layout.  Both device trees are freed before the
     merged one is placed, so the resident cache never exists twice.
 
-    The ``serve.graft`` span records the bytes over the host link
-    (``d2h_bytes``, ``h2d_bytes``: every leaf's global size).  Its
-    ``fetch`` part also waits for the prefill that made ``small``; its
-    ``place`` part ends when ``device_put`` returns, so the rest of the
-    copy is waited for by the next decode step."""
+    Migration's graft: its source cache lives on a mesh that is being
+    torn down, and may already be on the host.  The ``serve.graft`` span
+    records the bytes over the host link (``d2h_bytes``, ``h2d_bytes``:
+    every leaf's global size).  Its ``fetch`` part also waits for the
+    computation that made ``small``; its ``place`` part ends when
+    ``device_put`` returns, so the rest of the copy is waited for by the
+    next decode step."""
     from repro.models import lm
     info: dict = {}
     with span("serve.graft", info):
@@ -112,6 +114,157 @@ def _graft_to_device(big, small, slots, rows, sharding):
     return placed
 
 
+def _axis_names(entry) -> tuple:
+    """The mesh axes one PartitionSpec entry shards over."""
+    if entry is None:
+        return ()
+    return tuple(entry) if isinstance(entry, tuple) else (entry,)
+
+
+def _head_pieces(d_len: int, s_len: int, nq: int) -> list:
+    """How the common head ``[0, w)`` of axis 2 moves from shards of
+    ``s_len`` positions to shards of ``d_len`` positions (``nq`` shards
+    each): ``(source shard, source offset, destination shard, destination
+    offset, length)`` per piece that lies inside one shard of each.  Equal
+    lengths are equal global sizes: every shard copies its own block
+    (shard None)."""
+    if d_len == s_len:
+        return [(None, 0, None, 0, d_len)]
+    w = min(d_len, s_len) * nq
+    cuts = sorted({0, w} | set(range(s_len, w, s_len))
+                  | set(range(d_len, w, d_len)))
+    return [(a // s_len, a % s_len, a // d_len, a % d_len, b - a)
+            for a, b in zip(cuts, cuts[1:])]
+
+
+def _slab_at(x, i, off: int, n: int):
+    """Start and size of batch row ``i``'s ``n`` positions of axis 2 from
+    ``off`` (every cache leaf is [reps, batch, axis 2, ...]).  The start
+    is int32 throughout, as ``i`` is, also where x64 is on."""
+    start = [0, i, off] + [0] * (x.ndim - 3)
+    return (tuple(jnp.asarray(v, jnp.int32) for v in start),
+            (x.shape[0], 1, n) + x.shape[3:])
+
+
+def _build_slot_scatter(sharding):
+    """The jitted graft of :func:`_graft_to_device` for one decode layout.
+
+    Per device (``shard_map``), for each of the ``max_batch`` entries of
+    ``slots``/``rows``: the row's piece moves to the batch shard that
+    holds the slot (``ppermute`` over the batch axes, one shift per
+    shard) and, where axis 2's shards differ in length, to the sequence
+    shard that holds its positions (``ppermute`` over those axes, from
+    the static shapes).  The shard that holds the slot writes it in place;
+    a slot of ``max_batch`` or more is padding and writes nothing."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+    mesh = jax.tree.leaves(sharding)[0].mesh
+    specs = jax.tree.map(lambda s: s.spec, sharding)
+    sizes = dict(mesh.shape)
+
+    def size(names):
+        return math.prod(sizes[n] for n in names)
+
+    def index(names):
+        return lax.axis_index(names) if size(names) > 1 else 0
+
+    def leaf(d, s, spec, slot, row):
+        spec = tuple(spec) + (None,) * (d.ndim - len(spec))
+        b_names = _axis_names(spec[1])
+        nb, bd, bs = size(b_names), d.shape[1], s.shape[1]
+        my_b = index(b_names)
+        own_slot = (slot < nb * bd) & (my_b == slot // bd)
+        q_names = _axis_names(spec[2])
+        nq = size(q_names)
+        for src, soff, dst, doff, n in _head_pieces(d.shape[2], s.shape[2],
+                                                    nq):
+            start, shape = _slab_at(s, row % bs, soff, n)
+            piece = lax.dynamic_slice(s, start, shape)
+            if nb > 1:
+                got = piece
+                for o in range(1, nb):
+                    sent = lax.ppermute(piece, b_names,
+                                        [(i, (i + o) % nb)
+                                         for i in range(nb)])
+                    got = jnp.where((my_b - o) % nb == row // bs, sent, got)
+                piece = got
+            own = own_slot
+            if src is not None and nq > 1:
+                if src != dst:
+                    piece = lax.ppermute(piece, q_names, [(src, dst)])
+                own = own & (index(q_names) == dst)
+            start, _ = _slab_at(d, slot % bd, doff, n)
+            old = lax.dynamic_slice(d, start, piece.shape)
+            d = lax.dynamic_update_slice(
+                d, jnp.where(own, piece.astype(d.dtype), old), start)
+        return d
+
+    def local(big, small, slots, rows):
+        flat, tree = jax.tree.flatten(big)
+        flat_s = tree.flatten_up_to(small)
+        flat_p = tree.flatten_up_to(specs)
+
+        def one(j, flat):
+            return [leaf(d, s, p, slots[j], rows[j])
+                    for d, s, p in zip(flat, flat_s, flat_p)]
+
+        return tree.unflatten(lax.fori_loop(0, slots.shape[0], one, flat))
+
+    return jax.jit(jax.shard_map(local, mesh=mesh,
+                                 in_specs=(specs, specs, P(), P()),
+                                 out_specs=specs, check_vma=False),
+                   out_shardings=sharding, donate_argnums=0)
+
+
+_SCATTERS: dict = {}
+
+
+def _slot_scatter(sharding):
+    """The jitted graft for the decode layout ``sharding`` (one per layout;
+    it compiles once per prompt length)."""
+    leaves, tree = jax.tree.flatten(sharding)
+    key = (tree, tuple(leaves))
+    if key not in _SCATTERS:
+        _SCATTERS[key] = _build_slot_scatter(sharding)
+    return _SCATTERS[key]
+
+
+def _graft_bytes(big, small) -> int:
+    """Bytes one grafted row writes into ``big``: the common head of axis 2
+    where the two differ there, else the whole row."""
+    def row(d, s):
+        w = min(d.shape[2], s.shape[2])
+        return d.shape[0] * w * math.prod(d.shape[3:]) * d.dtype.itemsize
+    return sum(jax.tree.leaves(jax.tree.map(row, big, small)))
+
+
+def _graft_to_device(big, small, slots, rows, sharding):
+    """Graft ``small``'s batch ``rows`` (default: ``0..len(slots)-1``) into
+    ``big``'s batch ``slots`` on the device, with the semantics of
+    :func:`repro.models.lm.graft_cache_slots`, and keep the decode layout
+    ``sharding``.  ``big`` is donated and written in place; ``small`` is
+    freed.  Nothing crosses the host link but the two index vectors,
+    padded to ``max_batch`` so that every admitted count runs the one
+    program compiled for the prompt length.
+
+    The ``serve.graft`` span ends when the call returns (the next host
+    read of a device result waits for it); its info holds the bytes over
+    the host link (``d2h_bytes``, ``h2d_bytes``: 0), the admitted
+    ``rows`` and the ``device_bytes`` the scatter writes."""
+    slots = [int(t) for t in slots]
+    rows = list(range(len(slots))) if rows is None else [int(r) for r in rows]
+    n = jax.tree.leaves(big)[0].shape[1]
+    pad = n - len(slots)
+    info = {"d2h_bytes": 0, "h2d_bytes": 0, "rows": len(slots),
+            "device_bytes": _graft_bytes(big, small) * len(slots)}
+    with span("serve.graft", info):
+        placed = _slot_scatter(sharding)(
+            big, small, jnp.asarray(np.array(slots + [n] * pad, np.int32)),
+            jnp.asarray(np.array(rows + [0] * pad, np.int32)))
+    release(small)
+    return placed
+
+
 # ---------------------------------------------------------------------------
 # engine mode: real-model executor for the continuous-batching engine
 # ---------------------------------------------------------------------------
@@ -124,10 +277,10 @@ class JaxServeExecutor:
     ``max_batch`` shape (idle slots carry dummy tokens at ``cache_len=1``
     and are ignored); admission prefills the newly admitted prompts in
     one padded batch and grafts their prompt-window caches into the
-    resident max-seq cache at their slots
-    (:func:`repro.models.lm.graft_cache_slots`), leaving every other
-    in-flight request's state untouched.  Per-slot context positions go
-    into the decode step as the ``cache_len`` vector.
+    resident max-seq cache at their slots on the device
+    (:func:`_graft_to_device`), leaving every other in-flight request's
+    state untouched.  Per-slot context positions go into the decode step
+    as the ``cache_len`` vector.
 
     ``spans`` is the recorder (:mod:`repro.serve.spans`) that
     :meth:`ServeEngine.run` makes active for a run: the no-op one unless a
@@ -215,9 +368,9 @@ class JaxServeExecutor:
     def migrate(self, new_plan, mig, wafer=None):
         """Adopt a post-fault plan: rebuild the mesh/step functions for
         the new contract and graft the survivors' resident KV rows from
-        the old cache into their new slots
-        (:func:`repro.models.lm.graft_cache_slots` — the same primitive
-        admission uses, here with a slot→slot remap).
+        the old cache into their new slots on the host
+        (:func:`_graft_via_host`: the old cache leaves the device before
+        the new mesh is built, so admission's device graft cannot serve).
 
         Single-process scope: the degraded mesh is rebuilt over the same
         local device set (``make_plan_mesh`` folds the plan's ring degree
@@ -243,8 +396,8 @@ class JaxServeExecutor:
         if mig.survivors:
             slots = [new_slot for _, _, new_slot in mig.survivors]
             rows = [old_slot for _, old_slot, _ in mig.survivors]
-            self.caches = _graft_to_device(fresh, old_caches, slots, rows,
-                                           self._cache_sh)
+            self.caches = _graft_via_host(fresh, old_caches, slots, rows,
+                                          self._cache_sh)
         else:
             self.caches = fresh
         self.last_tok = np.zeros(new_plan.max_batch, np.int32)

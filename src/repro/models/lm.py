@@ -414,14 +414,16 @@ def graft_cache_slots(big, small, slots, rows=None):
     batch *slots* (axis 1 of every cache leaf — axis 0 is the layer-scan
     rep dim).
 
-    This is the continuous-batching admission primitive: a freshly
+    It states the continuous-batching admission move: a freshly
     prefilled request's prompt-window cache is merged into the resident
     max-seq decode cache at its assigned slot, leaving every other
-    in-flight request's state untouched.  Attention K/V leaves copy the
-    prompt window into the head of the slot's sequence axis; SSM
-    state/conv leaves (context-length-free) copy whole rows.  Operates on
-    host (numpy) trees — callers ``device_get`` / ``device_put`` around
-    it to respect the decode layout's shardings.
+    in-flight request's state untouched.  Admission itself runs it on the
+    device (``launch/serve.py:_graft_to_device``, tested bit for bit
+    against this).  Attention K/V leaves copy the prompt window into the
+    head of the slot's sequence axis; SSM state/conv leaves
+    (context-length-free) copy whole rows.  Operates on host (numpy)
+    trees — callers ``device_get`` / ``device_put`` around it to respect
+    the decode layout's shardings.
 
     It is also the KV *migration* move (elastic serving): with ``rows``
     given, survivors of a fault-triggered plan swap copy old-slot →

@@ -2,7 +2,8 @@
 
 The serve path marks its steps with :func:`span`: ``serve.iteration``
 (one engine loop pass), ``serve.prefill`` (the padded prefill's dispatch),
-``serve.graft`` with its ``fetch``/``merge``/``place`` parts, and
+``serve.graft`` (admission's scatter on the device; a migration's graft
+through the host adds ``fetch``/``merge``/``place`` parts), and
 ``serve.decode.wait`` (the host waiting for a decode step).  Where the
 spans go is the caller's choice: :meth:`repro.serve.engine.ServeEngine.run`
 activates its executor's ``spans`` attribute, any object with
@@ -11,8 +12,8 @@ no recorder active every span is one shared null context, so the off state
 costs a call per span.
 
 ``info`` is kept by reference until the span ends, so a span may pass a
-dict and fill it in from inside (the graft's byte counts are known only
-once the merged cache exists).
+dict and fill it in from inside (the host graft's byte counts are known
+only once the merged cache exists).
 """
 
 from __future__ import annotations

@@ -10,7 +10,8 @@ import pytest
 
 HERE = os.path.dirname(__file__)
 SCRIPTS = ["check_tatp.py", "check_model.py", "check_zigzag.py",
-           "check_wire_grads.py", "check_megatron.py", "check_serve.py"]
+           "check_wire_grads.py", "check_megatron.py", "check_serve.py",
+           "check_graft.py"]
 
 
 @pytest.mark.parametrize("script", SCRIPTS)

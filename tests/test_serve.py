@@ -428,3 +428,92 @@ def test_graft_cache_slots_touches_only_target_slots():
     np.testing.assert_array_equal(out["k"][:, 1, :4], small["k"][:, 0])
     np.testing.assert_array_equal(out["k"][:, 1, 4:], big["k"][:, 1, 4:])
     np.testing.assert_array_equal(out["state"][:, 3], small["state"][:, 1])
+
+
+# ---------------------------------------------------------------------------
+# admission's graft on the device (the executor's donated slot scatter)
+# ---------------------------------------------------------------------------
+
+GRAFT_BATCH, GRAFT_SEQ = 4, 32
+
+
+def _graft_trees(arch, window, seed=0):
+    """Seeded host trees of ``arch``'s decode cache at reduced widths: the
+    resident one (GRAFT_SEQ positions) and a prefill one (``window``),
+    with the one-device decode layout."""
+    import jax
+    from jax.sharding import NamedSharding
+    from repro.configs import get_reduced
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.core.dist import Dist, make_mesh
+    from repro.train.train_loop import cache_shapes, cache_specs
+    cfg = get_reduced(arch)
+    dist = Dist(make_mesh((1, 1), ("data", "model")))
+    rng = np.random.default_rng(seed)
+
+    def tree(seq):
+        shape = ShapeConfig("serve", "decode", seq, GRAFT_BATCH)
+        return jax.tree.map(
+            lambda a: rng.standard_normal(a.shape).astype(a.dtype),
+            cache_shapes(cfg, shape, dist))
+
+    shape = ShapeConfig("serve", "decode", GRAFT_SEQ, GRAFT_BATCH)
+    sharding = jax.tree.map(
+        lambda s: NamedSharding(dist.mesh, s),
+        cache_specs(cfg, shape, ParallelConfig(strategy="tatp"), dist))
+    return tree(GRAFT_SEQ), tree(window), sharding
+
+
+def _bits(a):
+    return np.ascontiguousarray(a).view(np.uint8)
+
+
+@pytest.mark.parametrize("window", [8, 20])
+@pytest.mark.parametrize("slots", [[2], [3, 0, 2], [2, 0, 3, 1]],
+                         ids=["k1", "k3", "kmax"])
+@pytest.mark.parametrize("arch", ["qwen2-72b", "mamba2-780m",
+                                  "zamba2-2.7b"])
+def test_device_graft_matches_the_host_graft(arch, slots, window):
+    """Bit for bit what ``graft_cache_slots`` makes on the host, on a GQA,
+    an SSM and a hybrid tree: the admitted rows' prompt window lands at
+    the head of their slots, every other slot and position is kept, the
+    layout too; the resident cache is donated and the prefill cache
+    freed."""
+    import jax
+    from repro.launch.serve import _graft_to_device
+    from repro.models.lm import graft_cache_slots
+    big, small, sharding = _graft_trees(arch, window)
+    want = graft_cache_slots(big, small, slots)
+    dev_big = jax.device_put(big, sharding)
+    dev_small = jax.device_put(small, sharding)
+    got = _graft_to_device(dev_big, dev_small, slots, None, sharding)
+    others = [i for i in range(GRAFT_BATCH) if i not in slots]
+    for g, w, b, sh in zip(jax.tree.leaves(got), jax.tree.leaves(want),
+                           jax.tree.leaves(big), jax.tree.leaves(sharding)):
+        assert g.sharding == sh
+        host = np.asarray(g)
+        assert np.array_equal(_bits(host), _bits(w))
+        assert np.array_equal(_bits(host[:, others]), _bits(b[:, others]))
+    assert all(x.is_deleted() for x in jax.tree.leaves((dev_big, dev_small)))
+
+
+def test_device_graft_compiles_once_for_every_admitted_count():
+    """The slot and row vectors are padded to max_batch: admitting 1 to
+    max_batch rows (in any slots, from any rows) runs one program."""
+    import jax
+    from repro.launch.serve import _graft_to_device, _slot_scatter
+    from repro.models.lm import graft_cache_slots
+    big, small, sharding = _graft_trees("qwen2-72b", 12)
+    caches = jax.device_put(big, sharding)
+    want = big
+    sizes = []
+    for k in range(1, GRAFT_BATCH + 1):
+        slots = list(range(GRAFT_BATCH))[::-1][:k]
+        rows = list(range(GRAFT_BATCH))[-k:]
+        caches = _graft_to_device(caches, jax.device_put(small, sharding),
+                                  slots, rows, sharding)
+        want = graft_cache_slots(want, small, slots, rows=rows)
+        sizes.append(_slot_scatter(sharding)._cache_size())
+    assert sizes == sizes[:1] * GRAFT_BATCH
+    for g, w in zip(jax.tree.leaves(caches), jax.tree.leaves(want)):
+        assert np.array_equal(_bits(np.asarray(g)), _bits(w))

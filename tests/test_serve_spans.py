@@ -42,31 +42,20 @@ REQS = [Request(rid=0, arrival=0.0, prompt_len=6, max_new_tokens=3),
         Request(rid=2, arrival=0.0, prompt_len=6, max_new_tokens=4)]
 
 
-def serve(recorder, monkeypatch=None):
-    """Serve REQS on a small model; returns (tokens by rid, byte counts
-    of each graft taken from the trees handed to the host merge)."""
+def serve(recorder):
+    """Serve REQS on a small model; returns (tokens by rid, the
+    executor)."""
     from repro.configs import get_reduced
     from repro.launch.serve import JaxServeExecutor
-    from repro.models import lm
     cfg = get_reduced("deepseek-7b")
     plan = compile_serve_plan(Wafer(WaferSpec()), cfg, 2, 16,
                               use_cache=False)
     ex = JaxServeExecutor(plan, cfg)
-    moved = []
-    if monkeypatch is not None:
-        merge = lm.graft_cache_slots
-
-        def counted(big, small, slots, rows=None):
-            out = merge(big, small, slots, rows=rows)
-            moved.append((nbytes((big, small)), nbytes(out)))
-            return out
-
-        monkeypatch.setattr(lm, "graft_cache_slots", counted)
     if recorder is not None:
         ex.spans = recorder
     eng = ServeEngine(plan, ex, clock=VirtualClock())
     assert eng.run(REQS).n_finished == len(REQS)
-    return {st.req.rid: list(st.tokens) for st in eng.sched.finished}, moved
+    return {st.req.rid: list(st.tokens) for st in eng.sched.finished}, ex
 
 
 def nbytes(tree):
@@ -76,33 +65,32 @@ def nbytes(tree):
 
 @pytest.fixture(scope="module")
 def recorded():
-    mp = pytest.MonkeyPatch()
     rec = ListRecorder()
-    try:
-        tokens, moved = serve(rec, mp)
-    finally:
-        mp.undo()
-    return rec, tokens, moved
+    tokens, ex = serve(rec)
+    return rec, tokens, ex
 
 
 def test_each_admission_and_decode_records_its_steps(recorded):
-    rec, _, moved = recorded
+    import jax
+    rec, _, ex = recorded
     its = rec.named("serve.iteration")
     prefills = rec.named("serve.prefill")
     grafts = rec.named("serve.graft")
-    assert len(prefills) == len(grafts) == len(moved) == 2
+    assert len(prefills) == len(grafts) == 2
     assert [p[3] for p in prefills] == [{"rows": 2, "padded_rows": 2},
                                         {"rows": 1, "padded_rows": 2}]
-    for p, g, (d2h, h2d) in zip(prefills, grafts, moved):
+    # a grafted row writes the prompt window of every K/V leaf
+    plen = REQS[0].prompt_len
+    row = sum(a.shape[0] * plen * a.shape[3] * a.shape[4] * a.dtype.itemsize
+              for a in jax.tree.leaves(ex.caches))
+    for p, g, k in zip(prefills, grafts, (2, 1)):
         # the prefill, then the graft, in one iteration
         assert p[2] <= g[1]
         assert any(inside(p, it) and inside(g, it) for it in its)
-        parts = [r for r in rec.rows if inside(r, g) and r is not g]
-        assert [r[0] for r in parts] == ["serve.graft.fetch",
-                                         "serve.graft.merge",
-                                         "serve.graft.place"]
-        assert g[3] == {"d2h_bytes": d2h, "h2d_bytes": h2d}
-        assert h2d > 0 and d2h > h2d  # the resident cache and the rows
+        # on the device: nothing over the host link, no host parts
+        assert [r for r in rec.rows if inside(r, g) and r is not g] == []
+        assert g[3] == {"d2h_bytes": 0, "h2d_bytes": 0, "rows": k,
+                        "device_bytes": k * row}
     # two rows live in every step: 0 and 1 for two, 1 and 2 for three;
     # each step's wait lies in an iteration of its own
     waits = rec.named("serve.decode.wait")
@@ -111,8 +99,34 @@ def test_each_admission_and_decode_records_its_steps(recorded):
                   if any(inside(w, it) for w in waits)) == [1] * 5
     assert {r[0] for r in rec.rows} == {
         "serve.iteration", "serve.prefill", "serve.graft",
-        "serve.graft.fetch", "serve.graft.merge", "serve.graft.place",
         "serve.decode.wait"}
+
+
+def test_migration_keeps_the_host_graft_and_its_parts(recorded):
+    """Migration still grafts through the host: a slot swap records the
+    fetch, merge and place parts with the bytes both ways, and moves the
+    rows."""
+    import types
+
+    import jax
+    import numpy as np
+    _, _, ex = recorded
+    before = jax.device_get(ex.caches)
+    rec = ListRecorder()
+    with spans.recording(rec):
+        ex.migrate(ex.plan, types.SimpleNamespace(
+            survivors=((0, 0, 1), (1, 1, 0)), evicted=()))
+    (g,) = rec.named("serve.graft")
+    parts = [r for r in rec.rows if inside(r, g) and r is not g]
+    assert [r[0] for r in parts] == ["serve.graft.fetch",
+                                     "serve.graft.merge",
+                                     "serve.graft.place"]
+    # the fresh cache and the old one out, the merged one back
+    assert g[3] == {"d2h_bytes": 2 * nbytes(before),
+                    "h2d_bytes": nbytes(before)}
+    after = jax.device_get(ex.caches)
+    for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(before)):
+        np.testing.assert_array_equal(a[:, [1, 0]], b)
 
 
 def test_recording_changes_no_served_token(recorded):

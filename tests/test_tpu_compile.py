@@ -1,8 +1,9 @@
 """Compile the main path for a TPU v5e that is described, not attached.
 
 The Pallas kernels at real widths, and deepseek-7b's full-width serving
-steps (``make_serve_fns``' prefill and decode) on one described chip and on
-meshes of the described 2x2 host.  The TPU compiler refuses here what it
+steps (``make_serve_fns``' prefill and decode, and admission's graft into
+the resident cache) on one described chip and on meshes of the described
+2x2 host.  The TPU compiler refuses here what it
 would refuse on the chip: a block shape off the tiling, a kernel that
 cannot be partitioned, a program over the device's memory.  Nothing runs.
 
@@ -151,3 +152,47 @@ def test_deepseek_7b_serve_steps_fit_v5e(topo, mesh_shape):
         if name == "decode":
             # the donated resident cache must alias, not be copied
             assert compiled.memory_analysis().alias_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("mesh_shape", [(1, 1), (1, 4), (2, 2)],
+                         ids=["1chip", "ring4", "data2xring2"])
+def test_deepseek_7b_graft_scatters_in_place_on_v5e(topo, mesh_shape):
+    """Admission's graft at deepseek-7b-pp2's serving widths (15 layers,
+    8 slots of 1024, a 128-token prompt): the donated resident cache
+    aliases the output, temporaries stay a sliver of it, and no cache
+    leaf is gathered or exchanged all-to-all."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.configs.base import ParallelConfig, ShapeConfig
+    from repro.core.dist import Dist, make_mesh
+    from repro.launch.serve import _slot_scatter
+    from repro.train.train_loop import cache_shapes, cache_specs
+
+    cfg = dataclasses.replace(get_config("deepseek-7b"), n_layers=15)
+    n = mesh_shape[0] * mesh_shape[1]
+    dist = Dist(make_mesh(mesh_shape, ("data", "model"),
+                          devices=topo.devices[:n]))
+    slots = 8
+
+    def tree(seq):
+        shape = ShapeConfig("serve", "decode", seq, slots)
+        specs = cache_specs(cfg, shape, ParallelConfig(strategy="tatp"),
+                            dist)
+        sh = jax.tree.map(lambda s: NamedSharding(dist.mesh, s), specs)
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            cache_shapes(cfg, shape, dist), sh), sh
+
+    big, sharding = tree(1024)
+    small, _ = tree(128)
+    index = jax.ShapeDtypeStruct((slots,), jnp.int32)
+    compiled = _slot_scatter(sharding).lower(big, small, index,
+                                             index).compile()
+    resident = sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(big)) // n  # per chip
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= resident
+    assert mem.temp_size_in_bytes < resident // 10
+    hlo = compiled.as_text()
+    assert "all-gather" not in hlo and "all-to-all" not in hlo
