@@ -2,7 +2,10 @@
 
 Everything that belongs to a cell is found by name: the ``workloads``
 entry of ``BENCHMARK.json``, the configuration in
-``chipbench/configs/<config>.json``, the traffic mix in
+``chipbench/configs/<config>.json``, the module its ``"reference"``
+names in the ``references`` directory beside this file (the
+architecture's plain forward, weight leaves and operation counts), the
+traffic mix in
 ``chipbench/traffic/<mix>.json``, the cell's rate and limits in
 ``chipbench/cells/<workload>.json``, each per-layer metric in
 ``chipbench/metrics/<metric>.py`` and the device's peaks in
@@ -16,6 +19,7 @@ make_plan_mesh -> prefill -> graft into the resident cache -> decode.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import math
@@ -37,13 +41,12 @@ SENTINEL = 10 ** 9 + 1  # request id of the never-sent keep-alive request
 DECODE_PROGRAM = r"^jit__decode$"  # the program's jitted decode step
 SPAN_NAMES = ("engine.admit", "engine.wait", "executor.prefill",
               "executor.decode")
-# config-file key -> the program's ModelConfig field
-CONFIG_KEYS = {
-    "hidden_size": "d_model", "intermediate_size": "d_ff",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "num_hidden_layers": "n_layers", "vocab_size": "vocab_size",
-    "rms_norm_eps": "norm_eps", "rope_theta": "rope_theta",
-    "tie_word_embeddings": "tie_embeddings", "attention_bias": "qkv_bias",
+REFERENCES = HERE / "references"
+# published key every architecture has -> the program's ModelConfig field;
+# a reference module's KEYS add the keys of its architecture
+SHARED_KEYS = {
+    "hidden_size": "d_model", "num_hidden_layers": "n_layers",
+    "vocab_size": "vocab_size", "tie_word_embeddings": "tie_embeddings",
     "torch_dtype": "dtype",
 }
 
@@ -83,6 +86,11 @@ class Cell:
     def max_seq(self) -> int:
         return self.conf["serving"]["max_seq"]
 
+    @property
+    def ref(self):
+        """The configuration's reference module."""
+        return reference(self.conf)
+
 
 def load_cell(name: str) -> Cell:
     bench = load_json(ROOT / "BENCHMARK.json")
@@ -114,6 +122,27 @@ def load_metric(name: str):
     return mod.read
 
 
+@functools.cache
+def _load_reference(path: Path):
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench_ref_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[spec.name] = mod  # a dataclass looks up its module
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def reference(conf: dict):
+    """The module of the ``references`` directory that the configuration
+    names with ``"reference"``, loaded once per process."""
+    name = conf.get("reference")
+    path = REFERENCES / f"{name}.py"
+    if not name or not path.exists():
+        raise BenchError(f"{conf['name']}: no reference module {name!r} "
+                         f"({path})")
+    return _load_reference(path)
+
+
 def peaks_for(kind: str) -> dict:
     table = load_json(HERE / "peaks.json")
     if kind not in table:
@@ -135,22 +164,34 @@ def chips(n: int, require_tpu: bool = True):
     return devs[:n]
 
 
+def config_keys(conf: dict) -> dict:
+    """Published key -> ModelConfig field: the shared keys and those of
+    the configuration's reference module."""
+    return {**SHARED_KEYS, **reference(conf).KEYS}
+
+
 def model_config(conf: dict):
     """The program's ModelConfig for ``conf``; every published key the
-    file gives must reach the program unchanged."""
+    shared table and the reference module name must be in the file and
+    reach the program unchanged, and the reference must compute every
+    mechanism the program's configuration has."""
     from repro.configs import get_config
+    keys = config_keys(conf)
+    missing = [key for key in keys if key not in conf]
+    if missing:
+        raise BenchError(f"{conf['name']}: no {', '.join(missing)}")
     base = get_config(conf["arch"])
     cfg = dataclasses.replace(base, **{
-        field_: conf[key] for key, field_ in CONFIG_KEYS.items()
-        if key in conf})
-    for key, field_ in CONFIG_KEYS.items():
+        field_: conf[key] for key, field_ in keys.items()})
+    for key, field_ in keys.items():
         if getattr(cfg, field_) != conf[key]:
             raise BenchError(f"{conf['name']}: {key}={conf[key]} did not "
                              f"reach the program ({field_}="
                              f"{getattr(cfg, field_)})")
-    if cfg.act != "swiglu" or cfg.layer_pattern != "G":
-        raise BenchError(f"{conf['name']}: the reference covers dense "
-                         f"SwiGLU decoders only")
+    try:
+        reference(conf).accepts(cfg)
+    except ValueError as e:
+        raise BenchError(f"{conf['name']}: {e}") from None
     return cfg
 
 
@@ -444,7 +485,7 @@ def prepare(cell: Cell, seed: int, trace: bool, *, require_tpu=True,
     spans = Spans(annotate=trace)
     mesh = make_plan_mesh(plan, devices=devices)
     ex = bench_executor_class()(plan, cfg, mesh=mesh, seed=seed, spans=spans,
-                                log=since_start)
+                                leaves=cell.ref.LEAVES, log=since_start)
     if on_executor is not None:
         on_executor(ex)
     jax.block_until_ready((ex.params, ex.caches))
@@ -571,7 +612,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             log("control: the fp8 reference's first choices stand in for "
                 "the served tokens")
         t = time.perf_counter()
-        got = check.widest_gaps(cell.conf, seed, samples, control)
+        got = check.widest_gaps(cell.ref, cell.conf, seed, samples, control)
         log(f"check: {len(samples)} requests in slots "
             f"{sorted({st.slot for st in chosen})}, "
             f"{time.perf_counter() - t:.3f} s")

@@ -1,5 +1,5 @@
 """The comparison that decides ``correct``: served tokens against the
-float32 reference.
+float32 reference, the configuration's reference module.
 
 For each sampled request the reference reads the prompt followed by the
 tokens the program served (all but the last) and, at each position where
@@ -13,8 +13,6 @@ random weights near-ties are common and a rounding flips the argmax.
 from __future__ import annotations
 
 import numpy as np
-
-from chipbench import reference
 
 PAD_MULTIPLE = 128  # rows are padded to one length: few compiled shapes
 
@@ -33,19 +31,20 @@ def batch(samples):
     return tokens, targets
 
 
-def widest_gaps(conf: dict, seed: int, samples, control: bool = False):
-    """Widest gap of the served tokens.  With ``control`` the fp8 control
-    stands in the program's place: at the same positions of the same
-    prompts and served tokens, the tokens it puts first are measured
-    instead of the served ones.  Returns a dict of floats (logit units)."""
+def widest_gaps(ref, conf: dict, seed: int, samples, control: bool = False):
+    """Widest gap of the served tokens by the reference module ``ref``.
+    With ``control`` the fp8 control stands in the program's place: at the
+    same positions of the same prompts and served tokens, the tokens it
+    puts first are measured instead of the served ones.  Returns a dict of
+    floats (logit units)."""
     tokens, targets = batch(samples)
     served = targets >= 0
     picks = targets
     if control:
-        _, ctrl_first, _ = reference.score(conf, seed, tokens, targets[None],
-                                           quant="fp8")
+        _, ctrl_first, _ = ref.score(conf, seed, tokens, targets[None],
+                                     quant="fp8")
         picks = np.where(served, ctrl_first, -1)
-    best, _, got = reference.score(conf, seed, tokens, picks[None])
+    best, _, got = ref.score(conf, seed, tokens, picks[None])
     gaps = best - got[0]
     return {"max_logit_gap": float(gaps[served].max()),
             "tokens_checked": int(served.sum())}

@@ -3,7 +3,8 @@
 :class:`BenchExecutor` is the program's ``JaxServeExecutor`` with three
 changes, none of them on the device path: its weights are replaced by the
 benchmark's seeded ones (made on the device in one jitted call, under the
-program's own shardings), its prompts are drawn from (seed, request id),
+program's own shardings, with the reference module's ``leaves``), its
+prompts are drawn from (seed, request id),
 and each call into it is recorded as a host span.
 """
 
@@ -55,7 +56,7 @@ def bench_executor_class():
 
     class BenchExecutor(JaxServeExecutor):
         def __init__(self, plan, cfg, *, mesh, seed: int, spans: Spans,
-                     log=lambda what: None):
+                     leaves: dict, log=lambda what: None):
             super().__init__(plan, cfg, mesh=mesh)
             jax.block_until_ready((self.params, self.caches))
             log("the program's executor built")
@@ -65,7 +66,7 @@ def bench_executor_class():
             shardings = jax.tree.map(lambda a: a.sharding, self.params)
             release(self.params)
             self.params = weights.program_params(seed, like, shardings,
-                                                 cfg.vocab_size)
+                                                 cfg.vocab_size, leaves)
             self.seed = seed
             self.spans = spans
 

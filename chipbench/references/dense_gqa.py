@@ -1,10 +1,20 @@
-"""Plain float32 reference of a dense decoder with grouped-query attention.
+"""Reference module of the dense decoder with grouped-query attention.
 
-It follows the published description of the Llama-style block that
-DeepSeek-LLM and Qwen2 share: RMSNorm before attention and before the
-MLP, rotary position embedding (rotate-half form, base ``rope_theta``),
-causal grouped-query attention with optional q/k/v bias, a SwiGLU MLP
-(``down(silu(gate(h)) * up(h))``), a final RMSNorm and an untied head.
+A configuration names its reference module with ``"reference"``; the
+harness finds ``chipbench/references/<name>.py`` by that name.  A module
+gives the published keys it passes to the program (``KEYS``), the
+refusal of any mechanism it does not compute (``accepts``), its per-layer
+weight leaves (``LEAVES``), the plain forward that decides ``correct``
+(``score``), the operation and byte counts of the rooflines
+(``decode_flops``, ``decode_bytes``, ``prefill_flops``) and the cut of its
+widths to test size (``small``).
+
+This one follows the published description of the Llama-style block
+that DeepSeek-LLM and Qwen2 share: RMSNorm before attention and before
+the MLP, rotary position embedding (rotate-half form, base
+``rope_theta``), causal grouped-query attention with optional q/k/v bias,
+a SwiGLU MLP (``down(silu(gate(h)) * up(h))``), a final RMSNorm and an
+untied head.
 
 It imports nothing of the program under test.  Its weights come from
 :mod:`chipbench.weights` by seed, one layer at a time, in the served
@@ -16,6 +26,12 @@ one padded batch.
 ``quant="fp8"`` is the control: the same forward with every matrix
 product's operands rounded to float8 e4m3 with a scale per row or column,
 the step below the configuration's bfloat16.
+
+Operation and byte counts are for the model as the configuration states
+it: real rows only (no padded slots or padded prompt positions), each
+weight read once per step, and the keys and values of each live row at
+its own context length.  A matrix product of m x k by k x n counts 2mkn
+operations.
 """
 
 from __future__ import annotations
@@ -32,6 +48,51 @@ from chipbench import weights
 HIGHEST = jax.lax.Precision.HIGHEST
 E4M3_MAX = 448.0
 HEAD_CHUNK = 256  # positions per head matmul: bounds the logits' memory
+
+# published key -> the program's ModelConfig field, beside the keys every
+# architecture shares (chipbench/bench.py:SHARED_KEYS)
+KEYS = {
+    "intermediate_size": "d_ff", "num_attention_heads": "n_heads",
+    "num_key_value_heads": "n_kv_heads", "rms_norm_eps": "norm_eps",
+    "rope_theta": "rope_theta", "attention_bias": "qkv_bias",
+}
+# per-layer leaf -> (fixed id, standard deviation rule of chipbench.weights)
+LEAVES = {
+    "wq": (10, "fan_in"), "wk": (11, "fan_in"), "wv": (12, "fan_in"),
+    "wo": (13, "fan_in"), "ln": (14, "norm"),
+    "bq": (15, "bias"), "bk": (16, "bias"), "bv": (17, "bias"),
+    "mlp.w_up": (20, "fan_in"), "mlp.w_gate": (21, "fan_in"),
+    "mlp.w_down": (22, "fan_in"), "mlp.ln": (23, "norm"),
+}
+
+
+def accepts(cfg) -> None:
+    """Raise ``ValueError`` where the program's ModelConfig ``cfg`` has a
+    mechanism this reference does not compute."""
+    beyond = {
+        "family": cfg.family != "dense", "act": cfg.act != "swiglu",
+        "layer_pattern": cfg.layer_pattern != "G",
+        "sliding_window": cfg.sliding_window is not None,
+        "n_experts": cfg.n_experts != 0,
+        "attn_softcap": cfg.attn_softcap is not None,
+        "logit_softcap": cfg.logit_softcap is not None,
+        "scale_embed": cfg.scale_embed, "tie_embeddings": cfg.tie_embeddings,
+        "d_head": cfg.head_dim * cfg.n_heads != cfg.d_model,
+        "n_enc_layers": cfg.n_enc_layers != 0,
+        "frontend": cfg.frontend is not None,
+    }
+    if any(beyond.values()):
+        raise ValueError("the reference covers dense SwiGLU decoders only "
+                         f"(not {', '.join(k for k, v in beyond.items() if v)})")
+
+
+def small(conf: dict) -> dict:
+    """``conf`` with every width cut to test size; multi-head attention
+    stays multi-head and grouped stays grouped."""
+    kv = 4 if conf["num_key_value_heads"] == conf["num_attention_heads"] \
+        else 2
+    return dict(conf, hidden_size=64, intermediate_size=160,
+                num_attention_heads=4, num_key_value_heads=kv)
 
 
 @dataclass(frozen=True)
@@ -104,8 +165,8 @@ def _rope(x, pos, theta):
 
 def _layer_weights(base, layer, dims: Dims):
     dt = jnp.dtype(dims.dtype)
-    return {n: weights.leaf(base, n, s, dt, layer=layer).astype(jnp.float32)
-            for n, s in dims.layer_shapes().items()}
+    return {n: weights.leaf(base, n, s, dt, layer=layer, leaves=LEAVES)
+            .astype(jnp.float32) for n, s in dims.layer_shapes().items()}
 
 
 @functools.partial(jax.jit, static_argnames=("dims", "quant"))
@@ -202,3 +263,52 @@ def score(conf: dict, seed: int, tokens: np.ndarray, picks: np.ndarray,
     return (np.concatenate(best).reshape(b, t),
             np.concatenate(arg).reshape(b, t),
             got.reshape(len(picks), b, t))
+
+
+def _dims(conf) -> Dims:
+    return conf if isinstance(conf, Dims) else Dims.of(conf)
+
+
+def layer_matmul_params(conf) -> int:
+    m = _dims(conf)
+    q, kv = m.heads * m.hd, m.kv_heads * m.hd
+    return m.d * q + 2 * m.d * kv + q * m.d + 3 * m.d * m.ff
+
+
+def layer_params(conf) -> int:
+    """Every weight of one layer: projections, two norms, q/k/v bias."""
+    m = _dims(conf)
+    q, kv = m.heads * m.hd, m.kv_heads * m.hd
+    return layer_matmul_params(m) + 2 * m.d + (q + 2 * kv if m.bias else 0)
+
+
+def decode_flops(conf, ctx_lens) -> float:
+    """One decode step; ``ctx_lens`` is each live row's context including
+    the token it adds."""
+    m = _dims(conf)
+    q = m.heads * m.hd
+    per_row = 2 * (m.layers * layer_matmul_params(m) + m.d * m.vocab)
+    return float(sum(per_row + 4 * m.layers * q * c for c in ctx_lens))
+
+
+def decode_bytes(conf, ctx_lens, itemsize: int = 2) -> float:
+    """One decode step: every layer's weights, the final norm and the head
+    read once, one embedding row per live row, each row's cached keys and
+    values read at its own length, and its new key and value written."""
+    m = _dims(conf)
+    kv = m.kv_heads * m.hd
+    weights = m.layers * layer_params(m) + m.d + m.d * m.vocab
+    rows = len(ctx_lens)
+    cache = sum(2 * m.layers * kv * c for c in ctx_lens)  # c-1 read, 1 new
+    return float(itemsize * (weights + rows * m.d + cache))
+
+
+def prefill_flops(conf, prompt_len: int) -> float:
+    """One prompt: every position through every layer (causal attention
+    over the positions before it) and the head at the last position."""
+    m = _dims(conf)
+    q = m.heads * m.hd
+    p = prompt_len
+    return float(2 * p * m.layers * layer_matmul_params(m)
+                 + 4 * m.layers * q * p * (p + 1) // 2
+                 + 2 * m.d * m.vocab)

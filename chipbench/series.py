@@ -34,11 +34,12 @@ def seeds(text: str) -> list:
 
 
 def spread(xs: list) -> float:
-    """Interquartile range over the median."""
+    """Interquartile range over the median; nan where there is none (fewer
+    than two runs, or a median of 0, as of a counter that reads nothing)."""
     if len(xs) < 2:
         return float("nan")
     q1, q2, q3 = statistics.quantiles(xs, n=4)
-    return (q3 - q1) / q2
+    return (q3 - q1) / q2 if q2 else float("nan")
 
 
 def main() -> None:

@@ -8,9 +8,11 @@ at each rate, from ``--start`` up by a factor of 1.25.  A rate holds when the
 queue at the window's close is no longer than at its midpoint.  The knee
 is the last rate that holds before the first that does not; the sweep
 stops after two rates in a row fail.  Prints one row per rate and, last,
-a JSON line with the table and the knee.  A cell's rate is then fixed in
-``chipbench/cells/<cell>.json`` from the knee (four fifths of it below
-the knee, five quarters above).
+a JSON line with the table, the knee and ``above``, five quarters of the
+lowest rate that did not hold.  A cell's rate is then fixed in
+``chipbench/cells/<cell>.json``: four fifths of the knee below it, and
+``above`` above it, so that the rate lies at least a quarter over
+capacity wherever in its step the knee falls.
 """
 
 import time
@@ -58,7 +60,9 @@ def main() -> None:
         fails = 0 if row["holds"] else fails + 1
         if fails == 2:
             break
+    fail = [r["rate"] for r in rows if not r["holds"]]
     print(json.dumps({"workload": args.workload, "knee": knee,
+                      "above": FACTOR * fail[0] if fail else None,
                       "rows": rows}))
 
 

@@ -16,9 +16,10 @@ for p in (str(ROOT), str(ROOT / "src")):
 def small_cell(config: str, mix: str, dtype: str = "bfloat16",
                limit: float = 0.06):
     """Configuration ``config`` under traffic ``mix`` at test size: every
-    width cut, the same code path.  Limit 0.06 logits: bf16 serving at
-    these widths reads 0.00-0.02, the fp8 control 0.1-0.2 (CPU runs,
-    d_model 64)."""
+    width cut by the configuration's reference module (``small``), two
+    layers, a small vocabulary; the same code path.  Limit 0.06 logits:
+    bf16 serving at these widths reads 0.00-0.02, the fp8 control 0.1-0.2
+    (CPU runs, d_model 64)."""
     import json
     from chipbench import bench, generator
     spec = json.loads((bench.ROOT / "BENCHMARK.json").read_text())
@@ -26,11 +27,8 @@ def small_cell(config: str, mix: str, dtype: str = "bfloat16",
                    bench.load_json(bench.HERE / "configs" / f"{config}.json"),
                    generator.load_mix(mix), {}, spec["per_layer"],
                    spec["end_to_end"])
-    conf = dict(c.conf, hidden_size=64, intermediate_size=160,
-                num_attention_heads=4, num_hidden_layers=2, vocab_size=512,
+    conf = dict(c.ref.small(c.conf), num_hidden_layers=2, vocab_size=512,
                 torch_dtype=dtype, serving={"max_batch": 4, "max_seq": 128})
-    conf["num_key_value_heads"] = 4 if c.conf["num_key_value_heads"] == \
-        c.conf["num_attention_heads"] else 2
     mix = dict(c.mix, preroll_s=1, check_tokens=60)
     if mix["prompt"].get("buckets"):
         mix["prompt"] = dict(mix["prompt"], buckets=[16, 32, 64], min=8,

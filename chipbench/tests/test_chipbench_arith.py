@@ -1,5 +1,6 @@
 """The benchmark's arithmetic: window metrics from request timestamps, and
-operation and byte counts against numbers worked by hand."""
+the dense reference module's operation and byte counts against numbers
+worked by hand."""
 
 import json
 import math
@@ -58,28 +59,30 @@ def conf(name):
 
 def test_deepseek_counts_by_hand():
     c = conf("deepseek-7b-pp2")
+    ref = bench.reference(c)
     # q, k, v, o: 4 * 4096^2 = 67,108,864; MLP 3 * 4096 * 11008 =
     # 135,266,304
-    assert flops.layer_matmul_params(c) == 202_375_168
-    assert flops.layer_params(c) == 202_375_168 + 2 * 4096
+    assert ref.layer_matmul_params(c) == 202_375_168
+    assert ref.layer_params(c) == 202_375_168 + 2 * 4096
     # one row at context 100: 2 * (15 layers + head 4096 * 102400) + QK and
     # PV 4 * 15 * 4096 * 100
-    assert flops.decode_flops(c, [100]) == 6_934_691_840
+    assert ref.decode_flops(c, [100]) == 6_934_691_840
     # bf16: weights + final norm + head + one embedding row + K and V of
     # 100 positions in 15 layers (32 heads x 128)
-    assert flops.decode_bytes(c, [100]) == 6_934_953_984
-    assert flops.prefill_flops(c, 128) == 779_988_500_480
+    assert ref.decode_bytes(c, [100]) == 6_934_953_984
+    assert ref.prefill_flops(c, 128) == 779_988_500_480
 
 
 def test_qwen_counts_by_hand():
     c = conf("qwen2-72b-tp4")
+    ref = bench.reference(c)
     # q, o 8192^2 each; k, v 8192 x 1024 each (8 KV heads x 128); MLP
     # 3 * 8192 * 29568
-    assert flops.layer_matmul_params(c) == 877_658_112
+    assert ref.layer_matmul_params(c) == 877_658_112
     # two norms (2 x 8192) and the q, k, v bias (8192 + 2 x 1024)
-    assert flops.layer_params(c) == 877_684_736
-    assert flops.decode_flops(c, [10, 20]) == 75_215_142_912
-    assert flops.decode_bytes(c, [10, 20]) == 37_601_312_768
+    assert ref.layer_params(c) == 877_684_736
+    assert ref.decode_flops(c, [10, 20]) == 75_215_142_912
+    assert ref.decode_bytes(c, [10, 20]) == 37_601_312_768
 
 
 def test_least_time_takes_the_larger_bound():
@@ -151,3 +154,4 @@ def test_series_spread_is_the_quartile_range_over_the_median():
     q1, q2, q3 = [10.75, 12.5, 15.5]  # statistics.quantiles, exclusive
     assert series.spread(xs) == pytest.approx((q3 - q1) / q2)
     assert math.isnan(series.spread([1.0]))
+    assert math.isnan(series.spread([0.0, 0.0, 0.0]))

@@ -1,13 +1,13 @@
-"""BENCHMARK.json and the files it names: every configuration, mix, cell
-and per-layer metric is found by name, and every name and unit keeps to
-the benchmark's character rules."""
+"""BENCHMARK.json and the files it names: every configuration, reference
+module, mix, cell and per-layer metric is found by name, and every name
+and unit keeps to the benchmark's character rules."""
 
 import json
 import re
 
 import pytest
 
-from chipbench import bench, flops, generator
+from chipbench import bench, generator, weights
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -69,12 +69,30 @@ def test_config_file(path):
         assert conf[key] == run != published
     for key in ("assumed", "deployment", "serving"):
         assert conf[key], key
-    for key in bench.CONFIG_KEYS:
+    for key in bench.config_keys(conf):
         assert key in conf, key
-    # the FLOP and byte functions read every configuration
-    assert flops.decode_flops(conf, [1]) > 0
-    assert flops.decode_bytes(conf, [1]) > 0
-    assert flops.prefill_flops(conf, 128) > 0
+
+
+@pytest.mark.parametrize("path", sorted((bench.HERE / "configs").glob(
+    "*.json")), ids=lambda p: p.stem)
+def test_config_names_a_reference_module_that_loads(path):
+    conf = json.loads(path.read_text())
+    ref = bench.reference(conf)
+    assert (bench.REFERENCES / f"{conf['reference']}.py").exists()
+    for name in ("KEYS", "LEAVES", "accepts", "score", "decode_flops",
+                 "decode_bytes", "prefill_flops", "small"):
+        assert hasattr(ref, name), name
+    # layer leaf ids: unique, and apart from the leaves every model shares
+    ids = [lid for lid, _ in ref.LEAVES.values()]
+    assert len(ids) == len(set(ids))
+    assert not set(ids) & {lid for lid, _ in weights.SHARED.values()}
+    assert not set(ref.LEAVES) & set(weights.SHARED)
+    assert not set(ref.KEYS) & set(bench.SHARED_KEYS)
+    # the FLOP and byte functions read the configuration
+    bigger = dict(conf, num_hidden_layers=2 * conf["num_hidden_layers"])
+    for f, args in ((ref.decode_flops, ([1],)), (ref.decode_bytes, ([1],)),
+                    (ref.prefill_flops, (128,))):
+        assert 0 < f(conf, *args) < f(bigger, *args)
 
 
 @pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
